@@ -12,11 +12,9 @@ the page size IS the KV-chunk batch size of the dense kernel's sweep.
 
 Each row reports mean dispatch microseconds for both lowerings and the
 derived ``gather/direct`` speed ratio (>1: the direct kernel wins by
-skipping the gathered copy).  The benchmark first attempts COMPILED
-execution (``interpret=False``) and falls back to interpret mode when
-no TPU backend is present (this container), tagging the row — the
-comparison still tracks the copy-vs-DMA structure, just through the
-interpreter.
+skipping the gathered copy).  The kernels compile on a TPU and run in
+interpret mode on the CPU (``repro.kernels.resolve_interpret``); each
+row is tagged with the mode, and only compiled rows time the device.
 
 Run standalone (``python -m benchmarks.table_paged_kernel``), via
 ``make bench-smoke`` (reduced sizes), or from benchmarks/run.py.
@@ -30,6 +28,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.decode_attention.ops import decode_attention_paged_op
 
 
@@ -62,26 +61,16 @@ def _time(fn, *args, iters=3, **kw):
 
 def rows(B=8, H=8, KV=2, Dh=64, S=512, num_pages=4096,
          page_sizes=(16, 32, 64), iters=3):
+    mode = "interpret" if resolve_interpret() else "compiled"
     out = []
     for ps in page_sizes:
         args = _inputs(B, H, KV, Dh, S, ps, num_pages)
-        mode = "compiled"
-        try:                       # production path: compiled kernels
-            us_direct = _time(decode_attention_paged_op, *args,
-                              interpret=False, iters=iters)
-            us_gather = _time(decode_attention_paged_op, *args,
-                              gather=True, interpret=False, iters=iters)
-        except Exception:          # no TPU backend: interpret fallback
-            mode = "interpret"
-            us_direct = _time(decode_attention_paged_op, *args,
-                              interpret=True, iters=iters)
-            us_gather = _time(decode_attention_paged_op, *args,
-                              gather=True, interpret=True, iters=iters)
+        us_direct = _time(decode_attention_paged_op, *args, iters=iters)
+        us_gather = _time(decode_attention_paged_op, *args, gather=True,
+                          iters=iters)
         # parity while we're here: both lowerings agree
-        a = decode_attention_paged_op(*args, interpret=(mode
-                                                        == "interpret"))
-        b = decode_attention_paged_op(*args, gather=True,
-                                      interpret=(mode == "interpret"))
+        a = decode_attention_paged_op(*args)
+        b = decode_attention_paged_op(*args, gather=True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4)
         tag = f"ps{ps}_{mode}"

@@ -1,8 +1,9 @@
 """End-to-end agentic kernel optimization with REAL kernel evaluation.
 
 Every candidate is a real config of the Pallas tiled-matmul template:
-validation BUILDS the kernel and checks it against the jnp oracle in
-interpret mode; profiling prices it with the TPU roofline cost model.
+validation BUILDS the kernel (Mosaic on a TPU, the interpreter on the
+CPU) and checks it against the jnp oracle; profiling prices it with the
+TPU roofline cost model.
 The search therefore optimizes a genuine kernel: watch the best block
 configuration improve over iterations.
 
@@ -14,7 +15,7 @@ mid-stream (the remaining tokens are never dispatched).  Pass ``sim``
 as the third argument to replay the scripted generation path instead.
 
 Evaluation is DEFERRED (DESIGN.md §Async-eval-plane): submission only
-queues a thunk, the interpret-mode build runs when the elastic pool
+queues a thunk, the kernel build runs when the elastic pool
 grants a device — overlapping the still-streaming reasoning trace —
 and same-build requests co-resident in the queue share one build;
 repeated configs across iterations replay from the bounded build-result

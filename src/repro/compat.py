@@ -1,40 +1,28 @@
-"""Version-compat shims over the pinned jax (0.4.37 on this image).
+"""Mesh and shard_map helpers over the installed jax (0.9).
 
-``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg on
-``jax.make_mesh`` / ``AbstractMesh``) only exist on newer jax; the
-sharding semantics we rely on (plain Auto axes) are the default on old
-versions, so the shim simply drops the kwarg when it is unsupported.
-Everything that builds a mesh — launch/mesh.py, tests — goes through
-these helpers instead of calling jax directly.
+Meshes are built with explicit ``Auto`` axis types, the sharding
+semantics the rules in ``distributed/sharding.py`` assume.  Everything
+that builds a mesh — launch/mesh.py, tests — goes through these helpers
+instead of calling jax directly.
 """
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Sequence
 
 import jax
 
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (new spelling, ``check_vma=``) falling back to
-    ``jax.experimental.shard_map`` (``check_rep=``) on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` without the varying-manual-axes check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` that passes Auto axis_types only when the
-    installed jax knows about them."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def device_mesh_shape(model: int = 1) -> int:
@@ -49,12 +37,7 @@ def device_mesh_shape(model: int = 1) -> int:
 
 def make_abstract_mesh(axis_shapes: Sequence[int],
                        axis_names: Sequence[str]):
-    """AbstractMesh across the 0.4.x ((name, size) pairs) and newer
-    (shape, names, *, axis_types) constructor signatures."""
-    pairs: Tuple[Tuple[str, int], ...] = tuple(
-        (n, s) for n, s in zip(axis_names, axis_shapes))
-    if HAS_AXIS_TYPE:
-        return jax.sharding.AbstractMesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.sharding.AbstractMesh(pairs)
+    """``AbstractMesh`` with Auto axis types."""
+    return jax.sharding.AbstractMesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

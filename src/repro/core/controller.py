@@ -12,7 +12,7 @@ no changes to the underlying LLM or search algorithm).  Per iteration:
     the scheduler's backpressure signal (``sched.pressure``),
   * submit emitted kernels to the ElasticScheduler as DEFERRED requests:
     the evaluation thunk runs when a device is granted (real mode: the
-    interpret-mode build overlaps the still-streaming reasoning
+    kernel build overlaps the still-streaming reasoning
     generation) and the EvalFuture resolves at completion; fallback
     kernels carry PRIO_FALLBACK and outrank queued speculative ones,
   * early-terminate the reasoning generation when a speculative kernel

@@ -15,7 +15,7 @@ from repro.kernels.decode_attention.ref import (decode_attention_ref,
 @functools.partial(jax.jit, static_argnames=("bkv", "use_pallas",
                                              "interpret"))
 def decode_attention_op(q, k, v, cache_len, *, bkv=128, use_pallas=True,
-                        interpret=True):
+                        interpret=None):
     if use_pallas:
         return decode_attention(q, k, v, cache_len, bkv=bkv,
                                 interpret=interpret)
@@ -26,7 +26,7 @@ def decode_attention_op(q, k, v, cache_len, *, bkv=128, use_pallas=True,
                                              "interpret"))
 def decode_attention_paged_op(q, k_pages, v_pages, block_table, cache_lens,
                               *, use_pallas=True, gather=False,
-                              interpret=True):
+                              interpret=None):
     """Block-table decode attention against the page-pool arenas.
 
     Three lowerings, one contract (q (B,H,Dh); arenas (P,ps,KV,Dh);

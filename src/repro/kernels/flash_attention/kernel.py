@@ -11,10 +11,13 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -32,11 +35,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bkv: int, seq: int,
 
     def body(ci, carry):
         acc, m_i, l_i = carry
-        # bare-int indices break pl.load on jax 0.4.x: use ds(0, 1)
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(ci * bkv, bkv),
-                            slice(None)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(ci * bkv, bkv),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(ci * bkv, bkv), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(ci * bkv, bkv), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (G, bq, bkv)
@@ -65,7 +65,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bkv: int, seq: int,
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     bq: int = 128, bkv: int = 128, causal: bool = True,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """q (B, S, H, Dh); k/v (B, S, KV, Dh) -> (B, S, H, Dh)."""
     B, S, H, Dh = q.shape
     KV = k.shape[2]
@@ -91,7 +91,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, G, bq, Dh), lambda b, i: (b, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KV, G, S, Dh), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qg, kk, vv)
     out = out.reshape(B, KV, G, S, Dh).transpose(0, 3, 1, 2, 4)
     return out.reshape(B, S, H, Dh)

@@ -12,7 +12,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 @functools.partial(jax.jit, static_argnames=("bq", "bkv", "causal",
                                              "use_pallas", "interpret"))
 def attention_op(q, k, v, *, bq=128, bkv=128, causal=True,
-                 use_pallas=True, interpret=True):
+                 use_pallas=True, interpret=None):
     if use_pallas:
         return flash_attention(q, k, v, bq=bq, bkv=bkv, causal=causal,
                                interpret=interpret)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _epilogue(x, kind: str, scale: float):
     if kind == "relu":
@@ -49,6 +51,7 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int, epilogue: str,
 
     acc_ref[...] += jnp.dot(
         a_ref[...].astype(jnp.float32), b_ref[...].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -64,7 +67,7 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int, epilogue: str,
 
 def matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128, bn: int = 128,
            bk: int = 128, epilogue: str = "none", scale: float = 1.0,
-           mask: Optional[str] = None, interpret: bool = True,
+           mask: Optional[str] = None, interpret: Optional[bool] = None,
            out_dtype=None) -> jnp.ndarray:
     """C[M,N] = epilogue(A[M,K] @ B[K,N]) with optional triangular mask."""
     M, K = a.shape
@@ -86,5 +89,5 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

@@ -26,7 +26,7 @@ VMEM_BYTES = 128 * 1024 * 1024 // 2   # usable half of ~128MiB VMEM
 @functools.partial(jax.jit, static_argnames=(
     "bm", "bn", "bk", "epilogue", "mask", "interpret"))
 def matmul_op(a, b, *, bm=128, bn=128, bk=128, epilogue="none",
-              scale=1.0, mask=None, interpret=True):
+              scale=1.0, mask=None, interpret=None):
     return matmul(a, b, bm=bm, bn=bn, bk=bk, epilogue=epilogue,
                   scale=scale, mask=mask, interpret=interpret)
 

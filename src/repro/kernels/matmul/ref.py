@@ -9,7 +9,8 @@ import jax.numpy as jnp
 
 def matmul_ref(a, b, *, epilogue: str = "none", scale: float = 1.0,
                mask: Optional[str] = None, out_dtype=None):
-    c = jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32))
+    c = jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
     if epilogue == "relu":
         c = jnp.maximum(c, 0.0)
     elif epilogue == "leaky_relu":

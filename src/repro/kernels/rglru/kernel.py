@@ -12,11 +12,14 @@ the cross-block parallelism still comes from the (B,) grid axis.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _rglru_kernel(a_ref, b_ref, y_ref, h_ref, *, bs: int):
@@ -43,7 +46,7 @@ def _rglru_kernel(a_ref, b_ref, y_ref, h_ref, *, bs: int):
 
 
 def rglru_scan(a: jnp.ndarray, b: jnp.ndarray, *, block: int = 128,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: Optional[bool] = None) -> jnp.ndarray:
     """h_t = a_t * h_{t-1} + b_t over axis 1.  a/b (B, S, R); h_0 = 0."""
     B, S, R = a.shape
     assert S % block == 0
@@ -58,5 +61,5 @@ def rglru_scan(a: jnp.ndarray, b: jnp.ndarray, *, block: int = 128,
         out_specs=pl.BlockSpec((1, block, R), lambda bi, si: (bi, si, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, R), b.dtype),
         scratch_shapes=[pltpu.VMEM((R,), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

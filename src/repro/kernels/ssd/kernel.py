@@ -11,11 +11,14 @@ SSD kernels (hardware adaptation per DESIGN.md).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, hout_ref,
@@ -66,7 +69,7 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, hout_ref,
 
 def ssd_scan(x: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray,
              dt: jnp.ndarray, a: jnp.ndarray, *, chunk: int = 64,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """x (B,S,HS,P); b/c (B,S,N); dt (B,S,HS); a (HS,) negative decays.
     Returns y (B,S,HS,P), h_final (B,HS,N,P)."""
     B, S, HS, P = x.shape
@@ -99,7 +102,7 @@ def ssd_scan(x: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray,
             jax.ShapeDtypeStruct((B * HS, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xs, bs, cs, dts, aa)
     y = y.reshape(B, HS, S, P).transpose(0, 2, 1, 3)
     return y, hout.reshape(B, HS, N, P)

@@ -5,8 +5,9 @@
         --termination hist-avg [--real-eval] [--devices 2]
 
 --real-eval validates candidates by BUILDING the Pallas matmul template
-(interpret mode) and profiling it with the TPU cost model; otherwise
-the calibrated simulation backend is used (deterministic, fast).
+(compiled on a TPU, interpreted on the CPU) and prices them with the TPU
+cost model; otherwise the calibrated simulation backend is used
+(deterministic, fast).
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ from repro.core.clock import EventLoop
 from repro.core.controller import SpecController, SpecGenConfig
 from repro.core.scheduler import ElasticScheduler, SchedulerConfig
 from repro.core.termination import CRITERIA
+from repro.launch.compile_cache import enable_compile_cache
 from repro.search.algorithms import ALGORITHMS
 from repro.search.llm_sim import SimEvalBackend, SimLLMBackend
 from repro.search.workload import WorkloadModel
@@ -46,6 +48,7 @@ def main() -> None:
     ap.add_argument("--real-eval", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     loop = EventLoop()
     wl = WorkloadModel(model=args.model, seed=args.seed)

@@ -7,19 +7,23 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.layers import Runtime
-from repro.models.registry import ARCH_IDS, get_smoke
+from repro.models.registry import ARCH_IDS, SIZES, get_sized
 from repro.models import schema
 from repro.serving.engine import Engine
 from repro.serving.kvcache import PrefixCacheStore
 
 
-def serve_batch(arch: str = "qwen2-1.5b", *, num_requests: int = 8,
-                prompt_len: int = 32, max_new: int = 16,
-                shared_prefix: int = 16, seed: int = 0, verbose=True):
+def serve_batch(arch: str = "qwen2-1.5b", *, size: str = "smoke",
+                num_requests: int = 8, prompt_len: int = 32,
+                max_new: int = 16, shared_prefix: int = 16, seed: int = 0,
+                verbose=True):
     """Serve a batch of requests that share a prompt prefix — the
-    prefix cache turns the shared part into a single prefill."""
-    cfg = get_smoke(arch)
+    prefix cache turns the shared part into a single prefill.
+    ``size`` picks the model's widths (``registry.SIZES``); weights are
+    random, drawn from ``seed``."""
+    cfg = get_sized(arch, size)
     params = schema.init_params(cfg, jax.random.PRNGKey(seed))
     store = PrefixCacheStore(local_budget_bytes=1 << 28,
                              remote_budget_bytes=1 << 28)
@@ -60,12 +64,17 @@ def serve_batch(arch: str = "qwen2-1.5b", *, num_requests: int = 8,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b", choices=ARCH_IDS)
+    ap.add_argument("--size", default="smoke", choices=SIZES,
+                    help="smoke toy widths, or the published config")
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
-    serve_batch(args.arch, num_requests=args.requests,
-                prompt_len=args.prompt_len, max_new=args.max_new)
+    enable_compile_cache()
+    serve_batch(args.arch, size=args.size, num_requests=args.requests,
+                prompt_len=args.prompt_len, max_new=args.max_new,
+                seed=args.seed)
 
 
 if __name__ == "__main__":

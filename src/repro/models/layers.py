@@ -52,7 +52,7 @@ class Runtime:
     attn_impl: str = "auto"        # full | chunked | auto
     q_chunk: int = 4096
     full_attn_threshold: int = 8192
-    use_pallas: bool = False       # interpret-mode Pallas kernels (tests)
+    use_pallas: bool = False       # paged Pallas decode kernel (tests)
     remat: str = "none"            # none | layer | dots
     scan_layers: bool = False      # homogeneous archs only (real training)
     layer_barrier: bool = False    # optimization_barrier between layers:
@@ -414,7 +414,7 @@ def attention_paged(cfg, p, x, positions, shard, runtime: Runtime,
                  else write_active.astype(pos.dtype))
         out = decode_attention_paged_op(
             q[:, 0], new["k"], new["v"], block_table, pos + wrote,
-            use_pallas=True, interpret=True)[:, None].astype(q.dtype)
+            use_pallas=True)[:, None].astype(q.dtype)
         out = shard(out, "act_batch", "act_seq", "act_heads", None)
     else:
         ck = new["k"][block_table].reshape(B, -1, KV, Dh)

@@ -37,5 +37,16 @@ def get_smoke(arch: str) -> ModelConfig:
     return _load(arch).SMOKE
 
 
+# "smoke": the toy widths tests and golden traces use; "full": the
+# published config, as the chip runs it
+SIZES = ("smoke", "full")
+
+
+def get_sized(arch: str, size: str) -> ModelConfig:
+    if size not in SIZES:
+        raise ValueError(f"unknown size {size!r}; known: {SIZES}")
+    return get_config(arch) if size == "full" else get_smoke(arch)
+
+
 def list_archs() -> List[str]:
     return list(ARCH_IDS)

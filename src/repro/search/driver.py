@@ -35,8 +35,10 @@ def _make_transport(loop: EventLoop, sched: ElasticScheduler,
 # so ~reasoning_tokens real decode steps x decode_step_s lands near the
 # sim's ~700 s mean reasoning duration — speculative forks then have
 # time to validate/profile BEFORE reasoning ends, so early termination
-# cancels REAL in-flight decode (tokens_not_decoded > 0).
-ENGINE_DEFAULTS = dict(arch="qwen2-1.5b", prompt_len=12,
+# cancels REAL in-flight decode (tokens_not_decoded > 0).  ``size``
+# picks the model's widths (``registry.SIZES``): the smoke toy, or the
+# published config with random weights drawn from the run's seed.
+ENGINE_DEFAULTS = dict(arch="qwen2-1.5b", size="smoke", prompt_len=12,
                        reasoning_tokens=40, spec_tokens=10,
                        decode_step_s=15.0)
 
@@ -48,10 +50,10 @@ def _make_engine(plane: TransportPlane, max_batch: int, opts: dict):
     import jax as _jax
     from repro.models import schema
     from repro.models.layers import Runtime
-    from repro.models.registry import get_smoke
+    from repro.models.registry import get_sized
     from repro.serving.engine import Engine
 
-    cfg = get_smoke(opts["arch"])
+    cfg = get_sized(opts["arch"], opts["size"])
     params = schema.init_params(cfg, _jax.random.PRNGKey(opts["seed"]))
     max_len = opts.get("max_len") or (opts["prompt_len"]
                                       + opts["reasoning_tokens"]
@@ -332,7 +334,8 @@ def run_traffic(arrivals, model: str = "glm", iterations: int = 2,
     return sched, adm, flows
 
 
-def run_engine_pool(arch: str = "qwen2-1.5b", n_workflows: int = 10,
+def run_engine_pool(arch: str = "qwen2-1.5b", size: str = "smoke",
+                    n_workflows: int = 10,
                     prompt_len: int = 16, reasoning_tokens: int = 24,
                     forks_per_workflow: int = 1, fork_tokens: int = 6,
                     max_len: int = 160, seed: int = 0,
@@ -360,10 +363,10 @@ def run_engine_pool(arch: str = "qwen2-1.5b", n_workflows: int = 10,
     import jax as _jax
     from repro.models import schema
     from repro.models.layers import Runtime
-    from repro.models.registry import get_smoke
+    from repro.models.registry import get_sized
     from repro.serving.engine import Engine
 
-    cfg = get_smoke(arch)
+    cfg = get_sized(arch, size)
     params = schema.init_params(cfg, _jax.random.PRNGKey(seed))
     loop = EventLoop()
     if trace:

@@ -2,12 +2,14 @@
 
 This is the non-simulated path of the pipeline: a candidate config from
 the LLM (scripted or real engine) is materialized as the tiled-matmul
-Pallas template, VALIDATED against the jnp oracle in interpret mode
+Pallas template, compiled ahead of time for the platform (Mosaic on a
+TPU, the interpreter on the CPU), VALIDATED against the jnp oracle
 (failure classes: build error / runtime error / numerical mismatch —
 same gates as the paper's nvcc + correctness check), and PROFILED with
-the analytic TPU roofline cost model (NCU stand-in).  Wall-clock
-durations are measured, so the same SpecController/ElasticScheduler
-code runs in real time (examples/kernel_search.py).
+the analytic TPU roofline cost model (NCU stand-in): profile numbers
+are priced, not measured.  Validation durations are measured wall
+time, so the same SpecController/ElasticScheduler code runs in real
+time (examples/kernel_search.py).
 
 Deferred execution (DESIGN.md §Async-eval-plane): ``submit_validate``/
 ``submit_profile`` package the build as a thunk that runs only when the
@@ -29,12 +31,14 @@ workflows; per-workflow hit rates are counted via ``Request.owner``.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import OrderedDict
 from typing import Dict, Optional, Tuple
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.types import (EvalFuture, KernelCandidate, ProfileResult,
                               ValidationResult, make_eval_request)
@@ -61,7 +65,7 @@ class _BatchCell:
 
 class RealEvalBackend:
     """Eval backend (sync + async protocols) over actual kernel builds
-    (interpret mode)."""
+    (compiled on a TPU, interpreted on the CPU)."""
 
     def __init__(self, atol: float = 2e-2, result_cache_size: int = 128,
                  result_cache_ttl: float = 600.0, clock=time.monotonic):
@@ -76,6 +80,8 @@ class RealEvalBackend:
         self.submits = 0                 # deferred submissions created
         self.builds_started = 0          # thunks that actually built
         self.batched_hits = 0            # followers served from a cell
+        self.builds_refused = 0          # builds the compiler refused
+        self.builds_passed = 0           # builds that matched the oracle
         self._pending: Dict[tuple, _BatchCell] = {}
         # cross-workflow build-result cache: build signature -> result,
         # LRU-bounded + TTL so stale prices age out (the cost model is
@@ -229,16 +235,22 @@ class RealEvalBackend:
         bm, bn, bk = int(cfg.get("bm", 64)), int(cfg.get("bn", 64)), \
             int(cfg.get("bk", 32))
         M, N, K = task.check_M, task.check_N, task.check_K
+        a, b, ref = self._check_inputs(task)
         try:
             if M % bm or N % bn or K % bk:
                 raise ValueError(
                     f"block {(bm, bn, bk)} does not divide {(M, N, K)}")
-            a, b, ref = self._check_inputs(task)
-            out = matmul(a, b, bm=bm, bn=bn, bk=bk,
-                         epilogue=task.epilogue, mask=task.mask)
-        except (ValueError, AssertionError) as e:
+            kernel = jax.jit(functools.partial(
+                matmul, bm=bm, bn=bn, bk=bk, epilogue=task.epilogue,
+                mask=task.mask)).lower(a, b).compile()
+        except Exception:                                  # noqa: BLE001
+            # the build boundary: whatever lowering or the compiler
+            # raises (tiling rules, VMEM limits, ...) is a build error
+            self.builds_refused += 1
             return (time.perf_counter() - t0,
                     ValidationResult(ok=False, failure="compile"))
+        try:
+            out = kernel(a, b).block_until_ready()
         except Exception:                                  # noqa: BLE001
             return (time.perf_counter() - t0,
                     ValidationResult(ok=False, failure="runtime"))
@@ -246,6 +258,7 @@ class RealEvalBackend:
         dur = time.perf_counter() - t0
         if not np.isfinite(err) or err > self.atol:
             return dur, ValidationResult(ok=False, failure="mismatch")
+        self.builds_passed += 1
         cost = estimate_cost(task.M, task.N, task.K, bm=bm, bn=bn, bk=bk,
                              mask=task.mask)
         ref_c = reference_cost(task.M, task.N, task.K, mask=task.mask)

@@ -452,3 +452,26 @@ def test_fork_does_not_mutate_backend_owned_spec_script():
     res = ctl.run_task("T1")
     assert llm.spec.duration == 50.0, "controller mutated the SpecScript"
     assert res.spec_tokens > 0                  # forks did happen + charge
+
+
+def test_real_eval_compiler_refusal_is_a_build_failure(monkeypatch):
+    """A tile the compiler refuses (Mosaic raises while lowering) is the
+    paper's build error, counted as a refusal — not a runtime error."""
+    from repro.search import real_eval
+
+    def refuse(*_a, **_kw):
+        raise ValueError("block shape must be divisible by 8 and 128")
+
+    monkeypatch.setattr(real_eval, "matmul", refuse)
+    ev = real_eval.RealEvalBackend()
+    _, res = ev.validate(cand("T6", bm=64, bn=64, bk=32))
+    assert not res.ok and res.failure == "compile"
+    assert (ev.builds_refused, ev.builds_passed) == (1, 0)
+
+
+def test_real_eval_counts_validated_builds():
+    from repro.search.real_eval import RealEvalBackend
+    ev = RealEvalBackend()
+    _, res = ev.validate(cand("T6", bm=128, bn=128, bk=128))
+    assert res.ok and res.speedup_firstcut > 0
+    assert (ev.builds_refused, ev.builds_passed) == (0, 1)

@@ -180,3 +180,15 @@ def test_rglru_strong_decay_underflow_guard():
     ref = rglru_ref(a, b)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(out, ref, atol=1e-3)
+
+
+def test_interpret_mode_follows_platform(monkeypatch):
+    from repro.kernels import resolve_interpret
+    assert resolve_interpret() is (jax.default_backend() == "cpu")
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(True) is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        resolve_interpret()

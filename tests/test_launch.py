@@ -1,7 +1,15 @@
-"""Launch-layer units: collective parsing, memory model, cell matrix."""
+"""Launch-layer units: collective parsing, memory model, cell matrix,
+compile-cache placement."""
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
 import numpy as np
 import pytest
 
+from repro.launch import compile_cache
 from repro.launch.dryrun import parse_collectives, _affine, model_flops
 from repro.launch.memmodel import estimate_memory
 from repro.launch.shapes import (SHAPES, all_cells, input_specs,
@@ -96,3 +104,37 @@ def test_model_flops_scaling():
     tr, pf = model_flops(cfg, "train_4k"), model_flops(cfg, "prefill_32k")
     assert 1.5 * pf < tr < 3.1 * pf
     assert model_flops(cfg, "decode_32k") < pf
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    root = Path(__file__).resolve().parents[1]
+    assert compile_cache.CHECKOUT_CACHE == root / ".jax_cache"
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_env_dir_is_written_and_nothing_set(tmp_path):
+    """With the variable set, the program sets nothing itself and the
+    compiled executable lands in the named directory."""
+    code = ("import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "before = jax.config.jax_compilation_cache_dir\n"
+            "print(enable_compile_cache())\n"
+            "assert jax.config.jax_compilation_cache_dir == before\n"
+            "jax.config.update("
+            "'jax_persistent_cache_min_compile_time_secs', 0)\n"
+            "jax.jit(lambda x: x + 1)(1).block_until_ready()\n")
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == str(tmp_path)
+    assert any(tmp_path.iterdir()), "nothing written to the cache dir"

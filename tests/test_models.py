@@ -282,6 +282,16 @@ def test_param_counts_sane():
     assert 95e9 < l4.param_count() < 115e9
 
 
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_sized_config_selects_smoke_or_published(arch):
+    from repro.models.registry import SIZES, get_sized
+    assert SIZES == ("smoke", "full")
+    assert get_sized(arch, "smoke") == get_smoke(arch)
+    assert get_sized(arch, "full") == get_config(arch)
+    with pytest.raises(ValueError, match="unknown size"):
+        get_sized(arch, "medium")
+
+
 def test_recurrentgemma_pattern():
     cfg = get_config("recurrentgemma-2b")
     kinds = cfg.layer_kinds()
