@@ -40,7 +40,7 @@ llm = sys.argv[3] if len(sys.argv) > 3 else "engine"
 evaluator = RealEvalBackend()
 res, sched, ctl = run_specgen(
     task, iterations=iters, devices=4, realloc="arrival-rate",
-    evaluator=evaluator, transport="async", llm=llm)
+    evaluator=evaluator, transport="async", llm=llm, trace=True)
 transport = ctl.transport
 
 # deferred-plane accounting: speculative validations GRANTED a device
@@ -87,8 +87,9 @@ for rec in res.records:
         continue
     lo, hi = rec.t_start, rec.t_start + rec.gen_time
     fetch_overlap += sum(
-        1 for (t, ev, tag, _n) in transport.link.trace
-        if ev == "start" and tag.startswith("prefix") and lo <= t < hi)
+        1 for (t, plane, ev, tag) in transport.loop.trace
+        if plane == "transport" and ev == "start"
+        and tag.split(":")[1].startswith("prefix") and lo <= t < hi)
 mean_fetch = res.prefix_fetch_s / max(res.prefix_fetches, 1)
 print(f"remote-KV transport: {res.prefix_fetches} prefix fetches "
       f"({transport.link.bytes_moved / 2**20:.1f} MiB moved, mean "

@@ -1,7 +1,10 @@
-"""Causal spans over the composed timeline (DESIGN.md §Observability).
+"""Spans: causal spans on the loop's clock, host spans on the profiler's.
 
-The composed ``(t, plane, event, tag)`` trace answers *what happened
-when*; spans answer *why*: every interval of interest — a workflow, a
+Two kinds of span, on two clocks, for two questions.
+
+**Causal spans on the loop's clock: the lifecycle audit.**  The
+composed ``(t, plane, event, tag)`` trace answers *what happened when*;
+causal spans answer *why*: every interval of interest — a workflow, a
 reasoning generation, a speculative fork, an eval request (and its
 device-execution sub-interval), a transport transfer, an engine decode
 step — is recorded as a ``Span`` with a PARENT edge to the span that
@@ -11,13 +14,15 @@ caused it, forming one causal tree per run:
                    └ eval ─ exec ─ build    (grant-time kernel build)
              engine row / step / park       (decode substrate)
 
-Spans are pure bookkeeping on the virtual clock: opening or closing one
-schedules NO loop events, consumes NO randomness and appends NOTHING to
-``loop.trace`` — the byte-pinned golden traces are untouched whether
-spans are enabled or not.  ``SpanRecorder`` is always present on an
-``EventLoop`` but disabled by default; ``EventLoop.enable_spans()``
-opts a run in, and call sites record unconditionally (a disabled
-recorder's ``open`` returns -1 and ``close`` no-ops).
+Spans are pure bookkeeping on the virtual clock (``loop.now``, where a
+decode step lasts ``decode_step_s``): their durations are never wall
+time.  Opening or closing one schedules NO loop events, consumes NO
+randomness and appends NOTHING to ``loop.trace`` — the byte-pinned
+golden traces are untouched whether spans are enabled or not.
+``SpanRecorder`` is always present on an ``EventLoop`` but disabled by
+default; ``EventLoop.enable_spans()`` opts a run in, and call sites
+record unconditionally (a disabled recorder's ``open`` returns -1 and
+``close`` no-ops).
 
 Causal parents cross module boundaries without widening every call
 signature via the CURRENT-PARENT cursor: the initiator brackets the
@@ -31,6 +36,15 @@ on every path — normal completion, early termination, fork-declined,
 eval abort, cancelled fetch, ``PagePoolExhausted`` rollback.
 ``unclosed_spans`` returns the offenders; ``double_closes`` counts
 close-after-close bugs (both must be empty/zero once a run finishes).
+
+**Host spans on the profiler's clock: time.**  ``host_span(name)`` is a
+``jax.profiler.TraceAnnotation`` around host work on the serving hot
+path (the engine's pump, the prefix store's page migrations).  A
+running profiler stamps it on the clock of the device trace, in the
+same ``.xplane.pb``, so every stretch the device sits idle can be put
+down to the host work around it.  There is no switch: with no profiler
+running, one enter and exit costs about a microsecond.  Every name is a
+constant of ``HOST_SPANS``, so no call site formats a string.
 """
 from __future__ import annotations
 
@@ -38,6 +52,37 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 ROOT = -1          # parent of top-level spans
+
+# Host spans on the profiler's clock, and what each covers:
+# one decode-step event of the engine's pump
+ENGINE_PUMP = "specgen.engine.pump"
+# token appends, retirements, subscriber callbacks
+ENGINE_COMPLETE = "specgen.engine.complete"
+# store lookups, suffix prefill, write-back, prefix capture
+ENGINE_ADMIT = "specgen.engine.admit"
+# page allocation, copy-on-write, the scrub and copy scatters
+ENGINE_PREPARE_WRITES = "specgen.engine.prepare_writes"
+# the batch arrays through the decode call
+ENGINE_LAUNCH = "specgen.engine.launch"
+# the host waiting for the step's tokens
+ENGINE_SYNC = "specgen.engine.sync"
+# one chunk of a prefix's pages moved host-side and released
+STORE_MIGRATE_CHUNK = "specgen.store.migrate_chunk"
+# the dispatch of a page read's gather
+POOL_READ_GATHER = "specgen.pool.read_gather"
+# the page read's device-to-host copies
+POOL_READ_COPY = "specgen.pool.read_copy"
+HOST_SPANS = (ENGINE_PUMP, ENGINE_COMPLETE, ENGINE_ADMIT,
+              ENGINE_PREPARE_WRITES, ENGINE_LAUNCH, ENGINE_SYNC,
+              STORE_MIGRATE_CHUNK, POOL_READ_GATHER, POOL_READ_COPY)
+
+
+def host_span(name: str):
+    """A host span on the profiler's clock (a context manager); ``name``
+    is one of ``HOST_SPANS``.  jax is imported here, not at the top, so
+    the simulator's core stays free of it."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
 
 @dataclasses.dataclass

@@ -49,7 +49,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.metrics import MetricsRegistry
-from repro.core.spans import ROOT, SpanRecorder
+from repro.core.spans import (ENGINE_ADMIT, ENGINE_COMPLETE, ENGINE_LAUNCH,
+                              ENGINE_PREPARE_WRITES, ENGINE_PUMP,
+                              ENGINE_SYNC, ROOT, SpanRecorder, host_span)
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.models.layers import Runtime
@@ -440,64 +442,65 @@ class Engine:
         first: a full hit restores shared pages with zero recompute; a
         partial hit suffix-prefills only the divergent remainder into
         fresh pages."""
-        take = list(pending)[: len(self._free)]
-        if not take:
-            return
-        self._ensure_cache()
-        groups: Dict[Tuple[int, int], List] = {}
-        for g in take:
-            n = g.prompt_len - 1        # decode consumes the last token
-            pf = self._awaiting_fetch.get(g.gen_id)
-            if pf is not None and pf.cancelled:
-                # the fetch was torn down underneath us (re-put of the
-                # key, sibling abort): drop the dead handle and re-probe
-                # the store like a fresh admission
-                del self._awaiting_fetch[g.gen_id]
-                pf.release_waiter(g.gen_id)
-                pf = None
-            if pf is not None:
-                if not pf.ready:
-                    continue            # pages still on the wire: stay
-                #                         pending, other rows decode on
-                del self._awaiting_fetch[g.gen_id]
-                pf.release_waiter(g.gen_id)
-                payload, clen = pf.payload, pf.length
-            elif n == 0:
-                payload, clen = None, 0
-            else:
-                payload, clen = self.store.get_longest(g.tokens[:n])
-                if isinstance(payload, PendingFetch):
-                    # future-backed remote hit: await it only when the
-                    # suffix prefill actually needs the pages — park the
-                    # admission, keep decoding everyone else
-                    payload.retain(g.gen_id)
-                    self._awaiting_fetch[g.gen_id] = payload
-                    self.fetch_deferrals += 1
-                    continue
-            if payload is not None:
-                pages, extra = payload.acquire()
-            else:
-                pages, extra, clen = [], None, 0
-            if clen >= n:                           # full hit / 1-token
-                self._admit_ready(g, n, pages, extra)
-            else:
-                self.store.note_recompute(n - clen)
-                groups.setdefault((clen, n), []).append(
-                    (g, pages, extra))
-        ordered = sorted(groups.items())
-        for gi, ((clen, n), items) in enumerate(ordered):
-            try:
-                self._admit_group(clen, n, items)
-            except PagePoolExhausted:
-                # _admit_group rolled its own items back; drop the
-                # acquired store refs of the still-unprocessed groups
-                # too so exhaustion never strands refcounts (the gens
-                # stay "pending" and can re-admit after pressure eases)
-                for _, later in ordered[gi + 1:]:
-                    for g, pages, _extra in later:
-                        if pages:
-                            self.pool.release(pages)
-                raise
+        with host_span(ENGINE_ADMIT):
+            take = list(pending)[: len(self._free)]
+            if not take:
+                return
+            self._ensure_cache()
+            groups: Dict[Tuple[int, int], List] = {}
+            for g in take:
+                n = g.prompt_len - 1        # decode consumes the last token
+                pf = self._awaiting_fetch.get(g.gen_id)
+                if pf is not None and pf.cancelled:
+                    # the fetch was torn down underneath us (re-put of the
+                    # key, sibling abort): drop the dead handle and re-probe
+                    # the store like a fresh admission
+                    del self._awaiting_fetch[g.gen_id]
+                    pf.release_waiter(g.gen_id)
+                    pf = None
+                if pf is not None:
+                    if not pf.ready:
+                        continue            # pages still on the wire: stay
+                    #                         pending, other rows decode on
+                    del self._awaiting_fetch[g.gen_id]
+                    pf.release_waiter(g.gen_id)
+                    payload, clen = pf.payload, pf.length
+                elif n == 0:
+                    payload, clen = None, 0
+                else:
+                    payload, clen = self.store.get_longest(g.tokens[:n])
+                    if isinstance(payload, PendingFetch):
+                        # future-backed remote hit: await it only when the
+                        # suffix prefill actually needs the pages — park the
+                        # admission, keep decoding everyone else
+                        payload.retain(g.gen_id)
+                        self._awaiting_fetch[g.gen_id] = payload
+                        self.fetch_deferrals += 1
+                        continue
+                if payload is not None:
+                    pages, extra = payload.acquire()
+                else:
+                    pages, extra, clen = [], None, 0
+                if clen >= n:                           # full hit / 1-token
+                    self._admit_ready(g, n, pages, extra)
+                else:
+                    self.store.note_recompute(n - clen)
+                    groups.setdefault((clen, n), []).append(
+                        (g, pages, extra))
+            ordered = sorted(groups.items())
+            for gi, ((clen, n), items) in enumerate(ordered):
+                try:
+                    self._admit_group(clen, n, items)
+                except PagePoolExhausted:
+                    # _admit_group rolled its own items back; drop the
+                    # acquired store refs of the still-unprocessed groups
+                    # too so exhaustion never strands refcounts (the gens
+                    # stay "pending" and can re-admit after pressure eases)
+                    for _, later in ordered[gi + 1:]:
+                        for g, pages, _extra in later:
+                            if pages:
+                                self.pool.release(pages)
+                    raise
 
     def _admit_ready(self, g: Generation, n: int, pages, extra) -> None:
         g.pages = pages
@@ -678,21 +681,22 @@ class Engine:
         dispatch: append a fresh page at a page boundary, and
         copy-on-write a page some other holder still references.  All
         page copies of the step batch into one scatter."""
-        pool, ps = self.pool, self.pool.page_size
-        srcs, dsts = [], []
-        for g in gens:
-            wp = g.pos // ps
-            if wp >= len(g.pages):
-                g.pages.append(pool.alloc(1)[0])
-            elif pool.refcount[g.pages[wp]] > 1:
-                new = pool.alloc(1)[0]
-                srcs.append(g.pages[wp])
-                dsts.append(new)
-                pool.release([g.pages[wp]])
-                g.pages[wp] = new
-        self._cache = pool.flush_scrub(self._cache)
-        if srcs:
-            self._cache = pool.copy_pages(self._cache, srcs, dsts)
+        with host_span(ENGINE_PREPARE_WRITES):
+            pool, ps = self.pool, self.pool.page_size
+            srcs, dsts = [], []
+            for g in gens:
+                wp = g.pos // ps
+                if wp >= len(g.pages):
+                    g.pages.append(pool.alloc(1)[0])
+                elif pool.refcount[g.pages[wp]] > 1:
+                    new = pool.alloc(1)[0]
+                    srcs.append(g.pages[wp])
+                    dsts.append(new)
+                    pool.release([g.pages[wp]])
+                    g.pages[wp] = new
+            self._cache = pool.flush_scrub(self._cache)
+            if srcs:
+                self._cache = pool.copy_pages(self._cache, srcs, dsts)
 
     def _dispatch(self, gens: Sequence[Generation]) -> None:
         """ONE jitted decode step advancing every generation in ``gens``
@@ -715,25 +719,27 @@ class Engine:
 
     def _dispatch_compute(self, gens: Sequence[Generation]):
         self._prepare_writes(gens)
-        B, W = self.max_batch, self.pool.pages_per_row
-        tok = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B,), np.int32)
-        act = np.zeros((B,), bool)
-        temp = np.zeros((B,), np.float32)
-        seeds = np.zeros((B,), np.uint32)
-        bt = np.zeros((B, W), np.int32)             # pad: null page 0
-        for g in gens:
-            tok[g.slot, 0] = g.tokens[g.pos]
-            pos[g.slot] = g.pos
-            act[g.slot] = True
-            temp[g.slot] = g.temperature
-            seeds[g.slot] = np.uint32(g.rng_seed & 0xFFFFFFFF)
-            bt[g.slot, : len(g.pages)] = g.pages
-        nxt, self._cache = self._decode(
-            self._dparams, jnp.asarray(tok), self._cache, jnp.asarray(bt),
-            jnp.asarray(pos), jnp.asarray(act), jnp.asarray(temp),
-            jnp.asarray(seeds))
-        nxt = np.asarray(nxt)
+        with host_span(ENGINE_LAUNCH):
+            B, W = self.max_batch, self.pool.pages_per_row
+            tok = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B,), np.int32)
+            act = np.zeros((B,), bool)
+            temp = np.zeros((B,), np.float32)
+            seeds = np.zeros((B,), np.uint32)
+            bt = np.zeros((B, W), np.int32)             # pad: null page 0
+            for g in gens:
+                tok[g.slot, 0] = g.tokens[g.pos]
+                pos[g.slot] = g.pos
+                act[g.slot] = True
+                temp[g.slot] = g.temperature
+                seeds[g.slot] = np.uint32(g.rng_seed & 0xFFFFFFFF)
+                bt[g.slot, : len(g.pages)] = g.pages
+            nxt, self._cache = self._decode(
+                self._dparams, jnp.asarray(tok), self._cache,
+                jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(act),
+                jnp.asarray(temp), jnp.asarray(seeds))
+        with host_span(ENGINE_SYNC):
+            nxt = np.asarray(nxt)
         self.decode_dispatches += 1
         if self.transport is not None:
             loop = self.transport.loop
@@ -753,26 +759,27 @@ class Engine:
         return nxt
 
     def _dispatch_complete(self, gens: Sequence[Generation], nxt) -> None:
-        self._spans.end(self._step_span)
-        self._step_span = -1
-        for g in gens:
-            if g.status != "running":
-                # cancelled between this step's compute and completion
-                # (early termination): its slot is already recycled —
-                # appending nxt[g.slot] would steal another row's token
-                continue
-            t = int(nxt[g.slot])
-            g.tokens.append(t)
-            g.emitted.append(t)
-            g.pos += 1
-            self.tokens_decoded += 1
-            if g.on_token is not None:
-                g.on_token(g, t)
-            if g.status != "running":
-                continue              # on_token cancelled this row
-            if len(g.emitted) >= g.max_new_tokens or \
-                    g.pos >= self.max_len - 1:
-                self._retire(g, "done")
+        with host_span(ENGINE_COMPLETE):
+            self._spans.end(self._step_span)
+            self._step_span = -1
+            for g in gens:
+                if g.status != "running":
+                    # cancelled between this step's compute and completion
+                    # (early termination): its slot is already recycled —
+                    # appending nxt[g.slot] would steal another row's token
+                    continue
+                t = int(nxt[g.slot])
+                g.tokens.append(t)
+                g.emitted.append(t)
+                g.pos += 1
+                self.tokens_decoded += 1
+                if g.on_token is not None:
+                    g.on_token(g, t)
+                if g.status != "running":
+                    continue              # on_token cancelled this row
+                if len(g.emitted) >= g.max_new_tokens or \
+                        g.pos >= self.max_len - 1:
+                    self._retire(g, "done")
 
     def step(self, gen_id: int) -> Optional[int]:
         """Advance one generation by one token; returns it (or None)."""
@@ -895,53 +902,54 @@ class Engine:
         self._pump_schedule(max(target - self.loop.now, 0.0))
 
     def _pump_step(self) -> None:
-        plane, loop, p = self.transport, self.loop, self._pump
-        p["scheduled"] = False
-        p["last_step"] = loop.now
-        if p["parked_at"] is not None:
-            plane.engine_blocked_s += loop.now - p["parked_at"]
-            p["parked_at"] = None
-            loop.record("engine", "wake", "")
-            self._spans.end(self._park_span)
-            self._park_span = -1
-        if p["inflight"] is not None:
-            # the dispatch launched one decode step ago completes NOW:
-            # token appends, retirements and the migrations they
-            # trigger land at the step's end, exactly where the stall
-            # path's post-tick completion put them
-            gens, nxt = p["inflight"]
-            p["inflight"] = None
-            self._dispatch_complete(gens, nxt)
-        pending = [g for g in self._gens.values()
-                   if g.status == "pending"]
-        if pending and self._free:
-            self._admit_all(pending)
-        live = [g for g in self._gens.values() if g.status == "running"]
-        if live:
-            p["inflight"] = (live, self._dispatch_compute(live))
-            self._pump_schedule(plane.cfg.decode_step_s)
-            return
-        if not any(g.status == "pending" for g in self._gens.values()):
-            return                              # idle: drained
-        if not (self._awaiting_fetch and plane.in_flight):
-            return                              # idle: blocked pendings
-        # every row is parked on the wire: arm wake-on-resolution for
-        # each distinct in-flight fetch job and go idle
-        p["parked_at"] = loop.now
-        loop.record("engine", "park",
-                    f"waiting={len(self._awaiting_fetch)}")
-        self._park_span = loop.spans.begin(
-            "engine", "park", f"waiting={len(self._awaiting_fetch)}",
-            parent=ROOT)
-        self._pump_armed = [j for j in self._pump_armed
-                            if not (j.done or j.cancelled)]
-        for pf in list(self._awaiting_fetch.values()):
-            job = pf.job
-            if job.done or job.cancelled or \
-                    any(j is job for j in self._pump_armed):
-                continue
-            self._pump_armed.append(job)
-            job.future.add_done_callback(self._on_fetch_landed)
+        with host_span(ENGINE_PUMP):
+            plane, loop, p = self.transport, self.loop, self._pump
+            p["scheduled"] = False
+            p["last_step"] = loop.now
+            if p["parked_at"] is not None:
+                plane.engine_blocked_s += loop.now - p["parked_at"]
+                p["parked_at"] = None
+                loop.record("engine", "wake", "")
+                self._spans.end(self._park_span)
+                self._park_span = -1
+            if p["inflight"] is not None:
+                # the dispatch launched one decode step ago completes NOW:
+                # token appends, retirements and the migrations they
+                # trigger land at the step's end, exactly where the stall
+                # path's post-tick completion put them
+                gens, nxt = p["inflight"]
+                p["inflight"] = None
+                self._dispatch_complete(gens, nxt)
+            pending = [g for g in self._gens.values()
+                       if g.status == "pending"]
+            if pending and self._free:
+                self._admit_all(pending)
+            live = [g for g in self._gens.values() if g.status == "running"]
+            if live:
+                p["inflight"] = (live, self._dispatch_compute(live))
+                self._pump_schedule(plane.cfg.decode_step_s)
+                return
+            if not any(g.status == "pending" for g in self._gens.values()):
+                return                              # idle: drained
+            if not (self._awaiting_fetch and plane.in_flight):
+                return                              # idle: blocked pendings
+            # every row is parked on the wire: arm wake-on-resolution for
+            # each distinct in-flight fetch job and go idle
+            p["parked_at"] = loop.now
+            loop.record("engine", "park",
+                        f"waiting={len(self._awaiting_fetch)}")
+            self._park_span = loop.spans.begin(
+                "engine", "park", f"waiting={len(self._awaiting_fetch)}",
+                parent=ROOT)
+            self._pump_armed = [j for j in self._pump_armed
+                                if not (j.done or j.cancelled)]
+            for pf in list(self._awaiting_fetch.values()):
+                job = pf.job
+                if job.done or job.cancelled or \
+                        any(j is job for j in self._pump_armed):
+                    continue
+                self._pump_armed.append(job)
+                job.future.add_done_callback(self._on_fetch_landed)
 
     def close_open_spans(self) -> None:
         """End-of-run span closure.  A pool run stops the shared loop

@@ -74,6 +74,7 @@ class CacheStats:
     tokens_reused: int = 0
     tokens_recomputed: int = 0
     migrations: int = 0
+    pages_migrated: int = 0     # paged payloads' pages moved host-side
     restores: int = 0
     bytes_migrated: int = 0
     evictions_local: int = 0
@@ -316,6 +317,7 @@ class PrefixCacheStore:
         whole rows."""
         if hasattr(entry.payload, "migrate_out"):
             entry.payload = entry.payload.migrate_out()
+            self.stats.pages_migrated += entry.payload.num_pages
         else:
             entry.payload = jax.tree.map(
                 lambda l: np.asarray(jax.device_get(l)), entry.payload)
@@ -347,6 +349,7 @@ class PrefixCacheStore:
 
             def mover(lo, hi):
                 payload.migrate_out_chunk(lo, hi)
+                self.stats.pages_migrated += hi - lo
 
             def on_done():
                 entry.payload = payload.migrate_out_finish()

@@ -32,6 +32,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import (POOL_READ_COPY, POOL_READ_GATHER,
+                              STORE_MIGRATE_CHUNK, host_span)
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.models.layers import EMPTY_SLOT
@@ -591,9 +593,11 @@ class PagePool:
     def read_pages(self, cache, pages: Sequence[int]):
         """Page contents -> host numpy (one dict per attention layer),
         the RDMA-out half of the store's local->remote migration."""
-        got = self._read_op(cache, jnp.asarray(list(pages), jnp.int32))
-        return [jax.tree.map(lambda a: np.asarray(jax.device_get(a)), d)
-                for d in got]
+        with host_span(POOL_READ_GATHER):
+            got = self._read_op(cache, jnp.asarray(list(pages), jnp.int32))
+        with host_span(POOL_READ_COPY):
+            return [jax.tree.map(lambda a: np.asarray(jax.device_get(a)),
+                                 d) for d in got]
 
     def _upload_impl(self, cache, host, pages):
         out = []
@@ -801,12 +805,13 @@ class PagedPrefix:
     def migrate_out(self):
         eng = self.engine
         self.wire_compress = False      # sync path: raw pages, always
-        data = eng.pool.read_pages(eng._cache, self.pages)
-        self.host = {"data": data, "n": list(self.pages)}
-        if self.extra is not None:
-            self.extra = jax.tree.map(
-                lambda l: np.asarray(jax.device_get(l)), self.extra)
-        eng.pool.release(self.pages)
+        with host_span(STORE_MIGRATE_CHUNK):
+            data = eng.pool.read_pages(eng._cache, self.pages)
+            self.host = {"data": data, "n": list(self.pages)}
+            if self.extra is not None:
+                self.extra = jax.tree.map(
+                    lambda l: np.asarray(jax.device_get(l)), self.extra)
+            eng.pool.release(self.pages)
         self.pages = []
         return self
 
@@ -850,12 +855,14 @@ class PagedPrefix:
 
         eng = self.engine
         ids = self._out_ids[lo:hi]
-        data = eng.pool.read_pages(eng._cache, ids)
-        if self.wire_compress:
-            data = compress_kv_pages(data)
-        for j in range(lo, hi):
-            self._out_data[j] = self._slice_pages(data, j - lo, j - lo + 1)
-        eng.pool.release(ids)
+        with host_span(STORE_MIGRATE_CHUNK):
+            data = eng.pool.read_pages(eng._cache, ids)
+            if self.wire_compress:
+                data = compress_kv_pages(data)
+            for j in range(lo, hi):
+                self._out_data[j] = self._slice_pages(data, j - lo,
+                                                      j - lo + 1)
+            eng.pool.release(ids)
 
     def migrate_out_finish(self):
         self.host = {"pages": self._out_data, "n": self._out_ids}

@@ -94,8 +94,10 @@ class TransportLink:
 
     Completion events live on the shared event loop, so link activity
     interleaves deterministically with scheduler grants and controller
-    events.  ``trace`` records every (t, event, tag, nbytes) — the
-    golden virtual-clock trace the determinism tests pin.
+    events.  With the loop's composed trace on (``loop.enable_trace``),
+    every enq/start/done/cancel lands there as a ``"transport"`` event
+    tagged ``<link>:<tag>:<nbytes>`` — the golden virtual-clock trace
+    the determinism tests pin.
     """
 
     def __init__(self, loop: EventLoop, spec: Optional[LinkSpec] = None,
@@ -115,7 +117,6 @@ class TransportLink:
         self.busy_total = 0.0
         self.queue_wait_total = 0.0
         self._t0 = loop.now
-        self.trace: List[tuple] = []
 
     # -------------------------------------------------------------- model
     def model_duration(self, nbytes: int) -> float:
@@ -131,10 +132,12 @@ class TransportLink:
 
     # ---------------------------------------------------------- lifecycle
     def _record(self, event: str, tag: str, nbytes: int) -> None:
-        self.trace.append((self.loop.now, event, tag, nbytes))
-        # composed timeline: the same event, attributed to this link,
-        # interleaves with engine steps and eval grants (core.trace)
-        self.loop.record("transport", event, f"{self.name}:{tag}:{nbytes}")
+        # composed timeline: the event, attributed to this link,
+        # interleaves with engine steps and eval grants (core.trace);
+        # the tag is built only when the loop records
+        if self.loop.trace is not None:
+            self.loop.record("transport", event,
+                             f"{self.name}:{tag}:{nbytes}")
 
     def submit(self, nbytes: int, tag: str = "") -> Transfer:
         t = Transfer(nbytes, tag, self.loop.now)

@@ -418,9 +418,17 @@ def test_pool_pressure_sheds_urgently_even_in_async_mode():
 
 
 # ------------------------------------------------- determinism (golden)
+def _transport_events(loop):
+    """(t, event, '<link>:<tag>:<nbytes>') of every link event on the
+    loop's composed trace."""
+    return [(t, ev, tag) for t, plane, ev, tag in loop.trace
+            if plane == "transport"]
+
+
 def _trace_run(seed):
     plane = make_plane(bandwidth=1e6, latency=0.01, jitter=0.2, seed=seed,
                        tier_bytes=50_000, backpressure="defer")
+    plane.loop.enable_trace()
     st = _store_with(plane, local=10_000)
     for i in range(6):
         st.put([i], payload(8000), length=1)
@@ -428,17 +436,18 @@ def _trace_run(seed):
     st.get([0])
     st.get([1])
     plane.drain()
-    return list(plane.link.trace)
+    return _transport_events(plane.loop)
 
 
 def test_golden_virtual_clock_trace_is_run_to_run_deterministic():
-    """Same seed => the full (time, event, tag, nbytes) link trace is
-    IDENTICAL, floats included.  (Legacy sync mode — no plane — must
-    reproduce the PR-3 golden fixtures: pinned in test_evalplane.py.)"""
+    """Same seed => the full (time, event, link:tag:nbytes) transport
+    trace is IDENTICAL, floats included.  (Legacy sync mode — no plane —
+    must reproduce the PR-3 golden fixtures: pinned in
+    test_evalplane.py.)"""
     a, b = _trace_run(3), _trace_run(3)
     assert a == b
     assert len(a) > 10
-    events = {e for _, e, _, _ in a}
+    events = {e for _, e, _ in a}
     assert {"enq", "start", "done"} <= events
     # jitter drew from the seeded stream: a different seed perturbs the
     # event times but not determinism
@@ -449,6 +458,7 @@ def test_golden_virtual_clock_trace_is_run_to_run_deterministic():
 def test_engine_async_trace_deterministic_across_runs():
     def run_once():
         plane = make_plane(prefill_tokens_per_s=1.0)
+        plane.loop.enable_trace()
         eng = make_engine(transport=plane)
         p = prompt(15)
         g1 = eng.submit(p, max_new_tokens=3, temperature=0.0)
@@ -456,9 +466,10 @@ def test_engine_async_trace_deterministic_across_runs():
         g2 = eng.submit(p, max_new_tokens=3, temperature=0.0)
         eng.run(g2)
         plane.drain()
-        return list(plane.link.trace)
+        return _transport_events(plane.loop)
 
-    assert run_once() == run_once()
+    a = run_once()
+    assert a and a == run_once()
 
 
 # --------------------------------------------- mid-flight edge cases
