@@ -172,6 +172,8 @@ class PagePool:
         self._dirty: set = set()                # freed-with-content pages
         self.page_copies = 0                    # CoW page copies (device)
         self.page_writes = 0                    # pages scattered into arenas
+        self.host_reads = 0                     # read_pages calls
+        self.host_copies = 0                    # arrays they copied to host
         self.reclaim = None                     # pressure hook: (need)->None
         # ---- jitted arena ops (memoized executables live on the pool);
         # each op has a per-layer-list impl and a fused-state impl — the
@@ -578,26 +580,31 @@ class PagePool:
         return dict(cache, arena=ar)
 
     # ------------------------------------------------- migration support
+    # A read gathers each leaf kind's pages stacked across attention
+    # layers, (A, n, ...): three device arrays whatever the depth.
     def _read_impl(self, cache, pages):
-        out = []
-        for i, c in enumerate(cache):
-            if i in self._attn_set:
-                out.append({k: a[pages] for k, a in c.items()})
-        return out
+        attn = [cache[i] for i in self._ranks]
+        return {k: jnp.stack([c[k][pages] for c in attn]) for k in attn[0]}
 
     def _read_fused_impl(self, cache, pages):
-        ar = cache["arena"]
-        return [{k: a[pages + r * self.num_pages] for k, a in ar.items()}
-                for r in range(self._A)]
+        ids = pages[None] + (jnp.arange(self._A, dtype=pages.dtype)
+                             * self.num_pages)[:, None]
+        return {k: a[ids] for k, a in cache["arena"].items()}
 
     def read_pages(self, cache, pages: Sequence[int]):
         """Page contents -> host numpy (one dict per attention layer),
-        the RDMA-out half of the store's local->remote migration."""
+        the RDMA-out half of the store's local->remote migration.  One
+        ``device_get`` starts every stacked leaf's copy before it waits
+        on any; the per-layer dicts are views of the host copies."""
+        self.host_reads += 1
+        if not self._A:
+            return []
         with host_span(POOL_READ_GATHER):
             got = self._read_op(cache, jnp.asarray(list(pages), jnp.int32))
         with host_span(POOL_READ_COPY):
-            return [jax.tree.map(lambda a: np.asarray(jax.device_get(a)),
-                                 d) for d in got]
+            host = jax.device_get(got)
+        self.host_copies += len(host)
+        return [{k: a[r] for k, a in host.items()} for r in range(self._A)]
 
     def _upload_impl(self, cache, host, pages):
         out = []

@@ -14,6 +14,8 @@ The acceptance bar for the paged refactor (DESIGN.md §Paged-KV /
   * the store counts page-level sharing between entries (CacheStats);
   * the fused on-device sampler matches its host references.
 """
+from types import SimpleNamespace
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -24,7 +26,7 @@ from repro.models.layers import Runtime
 from repro.models.registry import get_smoke
 from repro.serving.engine import Engine
 from repro.serving.kvcache import PrefixCacheStore
-from repro.serving.pagepool import PagePoolExhausted
+from repro.serving.pagepool import PagedPrefix, PagePool, PagePoolExhausted
 from repro.serving.sampler import (fold_in_keys, sample_token,
                                    sample_token_ref, sample_tokens)
 
@@ -253,6 +255,85 @@ def test_remote_migration_moves_pages_and_restores_bitwise():
     g2 = eng.submit(p, max_new_tokens=4, temperature=0.0)
     assert eng.run(g2) == out1
     assert eng.store.stats.restores >= 1
+
+
+def _filled_pool(layout):
+    """A pool whose arenas hold random bits, so a misplaced page shows."""
+    pool = PagePool(CFG, max_batch=2, max_len=64, page_size=8,
+                    layout=layout)
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 16))
+
+    def fill(a):
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            return jax.random.randint(next(keys), a.shape, 0, 1 << 20,
+                                      a.dtype)
+        return jax.random.normal(next(keys), a.shape, a.dtype)
+    return pool, jax.tree.map(fill, pool.init_cache())
+
+
+def _arena_pages(pool, cache, pages):
+    """Each attention layer's pages, indexed leaf by leaf on the device
+    and copied one leaf at a time."""
+    idx = np.asarray(pages)
+    if pool.layout == "fused":
+        return [{k: np.asarray(a[idx + r * pool.num_pages])
+                 for k, a in cache["arena"].items()}
+                for r in range(len(pool._ranks))]
+    return [{k: np.asarray(a[idx]) for k, a in cache[i].items()}
+            for i in pool._ranks]
+
+
+def _assert_bitwise(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert sorted(g) == sorted(w)
+        for k in w:
+            assert g[k].dtype == w[k].dtype and g[k].shape == w[k].shape
+            assert g[k].tobytes() == w[k].tobytes(), k
+
+
+@pytest.mark.parametrize("pages", [[3], [7, 2, 9, 4, 1]],
+                         ids=["1-page", "5-pages"])
+@pytest.mark.parametrize("layout", ["layers", "fused"])
+def test_read_pages_copies_stacked_leaves_bitwise(layout, pages):
+    """read_pages hands back, bitwise, the per-layer dicts a per-leaf
+    read gives, with three host copies a call (one per leaf kind)
+    whatever the number of attention layers."""
+    pool, cache = _filled_pool(layout)
+    want = _arena_pages(pool, cache, pages)
+    assert len(want) == 2 and len(want[0]) == 3      # 3·A = 6 leaves
+    for call in (1, 2):
+        got = pool.read_pages(cache, pages)
+        _assert_bitwise(got, want)
+        assert pool.host_reads == call
+        assert pool.host_copies == 3 * call
+
+
+@pytest.mark.parametrize("layout", ["layers", "fused"])
+def test_streamed_migrate_out_then_fetch_restores_pages_bitwise(layout):
+    """A streamed migrate-out in multi-page chunks, sliced per page on
+    the host, then fetched back in other chunk bounds, lands the same
+    bits in the fresh pages."""
+    pool, cache = _filled_pool(layout)
+    eng = SimpleNamespace(pool=pool, _cache=cache)
+    src = pool.alloc(5)
+    want = _arena_pages(pool, cache, src)
+    pre = PagedPrefix.capture(eng, src, None, length=5 * pool.page_size)
+    pool.release(src)                    # the entry holds the only refs
+    assert pre.migrate_out_begin() == 5
+    pre.migrate_out_chunk(0, 2)
+    pre.migrate_out_chunk(2, 5)
+    pre.migrate_out_finish()
+    assert pool.pages_in_use == 0 and pool.host_reads == 2
+    assert [len(d[0]["k"]) for d in pre.host["pages"]] == [1] * 5
+    # the released pages may come back: wipe the arenas first
+    eng._cache = jax.tree.map(jnp.zeros_like, eng._cache)
+    dst = pre.fetch_begin()
+    pre.fetch_chunk(0, 3)
+    pre.fetch_chunk(3, 5)
+    pre.fetch_finish()
+    assert pre.pages == dst and pre.on_device
+    _assert_bitwise(_arena_pages(pool, eng._cache, dst), want)
 
 
 # --------------------------------------------------- bucketed admission
