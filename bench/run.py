@@ -12,8 +12,9 @@ end-to-end metrics with ``--trace 0``, its per-layer metrics with
 Everything a cell is made of is found by name: the configuration file
 named in ``BENCHMARK.json``, the traffic file ``bench/traffic/<traffic>.json``
 whose ``kind`` names the driver ``bench/drivers/<kind>.py``, the
-reference ``bench/references/<reference>.py`` the configuration names,
-the limits ``bench/limits/<cell>.json`` and one reader
+architecture ``bench/arch/<arch>.py`` (shapes, weights, pool, work) and
+the reference ``bench/references/<reference>.py`` the configuration
+names, the limits ``bench/limits/<cell>.json`` and one reader
 ``bench/metrics/<metric>.py`` per per-layer metric.
 
 Set-up (``setup_s``) runs from the start of this process to the start of
@@ -51,9 +52,8 @@ if str(ROOT / "src") not in sys.path:
 # this long (a share of the window, at most TRACE_MAX_S seconds)
 TRACE_FROM, TRACE_SHARE, TRACE_MAX_S = 0.25, 0.5, 10.0
 
-
-class CellError(RuntimeError):
-    pass
+from bench.lib import arch                                 # noqa: E402
+from bench.lib.arch import CellError                       # noqa: E402,F401
 
 
 def load_spec(root: Path = ROOT) -> dict:
@@ -106,19 +106,7 @@ def program_config(config: dict):
 
 
 def check_widths(config: dict, pc) -> None:
-    d, h = config["hidden_size"], config["num_attention_heads"]
-    want = {
-        "num_layers": config["num_hidden_layers"], "d_model": d,
-        "num_heads": h, "num_kv_heads": config["num_key_value_heads"],
-        "head_dim": config.get("head_dim") or d // h,
-        "d_ff": config["intermediate_size"],
-        "vocab_size": config["vocab_size"],
-        "qkv_bias": bool(config.get("attention_bias", False)),
-        "qk_norm": bool(config.get("qk_norm", False)),
-        "rope_theta": float(config["rope_theta"]),
-        "norm_eps": float(config["rms_norm_eps"]),
-        "tie_embeddings": bool(config["tie_word_embeddings"]),
-    }
+    want = arch.module(config).widths(config)
     got = {k: getattr(pc, k) for k in want}
     if got != want:
         raise CellError(f"program config {pc.name} differs from the "
@@ -164,21 +152,17 @@ class Harness:
         return TracedLoop()
 
     def num_pages(self) -> int:
-        """Page pool sized from the HBM left after weights and workspace
-        (``assumed`` in the configuration file), not from max_batch x
-        max_len."""
+        """Page pool sized from the HBM left after weights, workspace
+        (``assumed`` in the configuration file) and the fixed-size state
+        of ``max_batch`` slots, not from max_batch x max_len."""
         from bench.lib import work
-        a = self.config["assumed"]
+        c, a = self.config, self.config["assumed"]
+        shapes = arch.module(c)
         hbm = work.peaks(self.device_kind)["hbm_bytes"]
-        weights = 2 * work.param_count(self.config)
-        c = self.config
-        dh = c.get("head_dim") or c["hidden_size"] // c[
-            "num_attention_heads"]
-        ps = a["page_size"]
-        page = c["num_hidden_layers"] * (
-            2 * ps * c["num_key_value_heads"] * dh * 2 + ps * 4)
-        return int((hbm * a["hbm_fill"] - weights - a["workspace_bytes"])
-                   // page)
+        free = (hbm * a["hbm_fill"] - 2 * work.param_count(c)
+                - a["workspace_bytes"]
+                - self.traffic["max_batch"] * shapes.state_bytes_per_row(c))
+        return int(free // shapes.page_bytes(c, a["page_size"]))
 
 
 class Window:

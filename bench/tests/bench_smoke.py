@@ -16,7 +16,7 @@ CONFIG = {"hidden_size": 96, "num_attention_heads": 6,
           "vocab_size": 512, "attention_bias": True,
           "rope_theta": 1000000.0, "rms_norm_eps": 1e-6,
           "tie_word_embeddings": True, "program_arch": "qwen2-1.5b",
-          "reference": "dense_gqa",
+          "arch": "dense_gqa", "reference": "dense_gqa",
           "assumed": {"page_size": 16, "hbm_fill": 0.001,
                       "workspace_bytes": 0}}
 

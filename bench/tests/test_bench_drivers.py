@@ -2,8 +2,11 @@
 schedules, the refusal of the CPU by the measurement path, the
 data-driven lookup of cells and metrics, and faults planted under the
 timed path that the comparison with the reference must catch."""
+import contextlib
 import json
 import shutil
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -150,6 +153,87 @@ def test_a_metric_is_added_by_files_alone(tmp_path):
     class Ctx:
         c0, c1 = {"x": 1}, {"x": 4}
     assert read(Ctx) == 3
+
+
+@contextlib.contextmanager
+def harness_at(root):
+    """The harness's modules imported from the copy at ``root``, for the
+    length of the block; the repository's own are put back after."""
+    def ours(name):
+        return name == "bench" or name.startswith("bench.")
+    saved = {k: sys.modules.pop(k) for k in list(sys.modules) if ours(k)}
+    sys.path.insert(0, str(root))
+    try:
+        yield
+    finally:
+        sys.path.remove(str(root))
+        for k in [k for k in sys.modules if ours(k)]:
+            del sys.modules[k]
+        sys.modules.update(saved)
+
+
+# the program's phi3.5-moe smoke preset, as a configuration file
+MOE = {"hidden_size": 64, "num_attention_heads": 4,
+       "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
+       "num_local_experts": 4, "num_experts_per_tok": 2,
+       "num_hidden_layers": 2, "vocab_size": 256,
+       "tie_word_embeddings": False, "program_arch": "phi3.5-moe-42b-a6.6b",
+       "arch": "sparse_moe", "reference": "dense_gqa",
+       "assumed": {"page_size": 16, "hbm_fill": 0.001,
+                   "workspace_bytes": 0}}
+
+
+def test_a_configuration_of_another_architecture_is_added_by_files_alone(
+        tmp_path):
+    """A configuration whose layers route over sparse experts: a new arch
+    module, a new configuration file and new entries in BENCHMARK.json,
+    no edit to any file the harness has.  The driver's set-up builds its
+    engine on weights that pass the program's schema, with the pool the
+    arch module sizes, and its step's work is counted by the module."""
+    from bench_smoke import REASON_FORK
+    from repro.models.registry import get_smoke
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "bench/tests/data/arch_sparse_moe.py",
+                tmp_path / "bench/arch/sparse_moe.py")
+    (tmp_path / "bench/configs/moe-smoke.json").write_text(json.dumps(MOE))
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "moe-smoke", "source": "test",
+                            "file": "bench/configs/moe-smoke.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "moe-smoke.reason-fork-w8",
+                              "config": "moe-smoke",
+                              "traffic": "reason-fork-w8", "chips": 1,
+                              "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    pc = get_smoke("phi3.5-moe-42b-a6.6b")
+    assert set(pc.layer_kinds()) == {"moe"}
+    with harness_at(tmp_path):
+        import importlib
+        from bench import run as run_copy
+        from bench.lib import work
+        moe = importlib.import_module("bench.arch.sparse_moe")
+        assert Path(moe.__file__).parent == tmp_path / "bench/arch"
+        _cell, _c, config, traffic = run_copy.resolve(
+            run_copy.load_spec(tmp_path), "moe-smoke.reason-fork-w8",
+            tmp_path)
+        assert config == MOE
+        traffic = {**traffic, **REASON_FORK}
+        run_copy.check_widths(config, pc)
+        h = run_copy.Harness(config, traffic, 9, False, pc, "TPU v5 lite")
+        drv = importlib.import_module(
+            f"bench.drivers.{traffic['kind']}").Driver(h)
+        drv.setup()                      # check_params passes inside
+        assert drv.params["layers"][0]["moe"]["router"].dtype == "float32"
+        pages = int((16e9 * 0.001 - 2 * moe.param_count(config))
+                    // moe.page_bytes(config, 16))
+        assert drv.eng.pool.num_pages == h.num_pages() == pages
+        assert moe.param_count(config) == pc.param_count()
+        ctx = [90, 200, 370]
+        assert work.decode_step_work(config, ctx) == \
+            moe.decode_step_work(config, ctx)
+        assert drv.eng.generation(drv.flows[0].reason).status == "running"
+        drv.release()
 
 
 def test_control_is_not_correct():

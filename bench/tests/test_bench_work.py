@@ -10,6 +10,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
+from bench.arch import dense_gqa  # noqa: E402
 from bench.lib import work  # noqa: E402
 
 QWEN2 = json.loads((ROOT / "bench/configs/qwen2-1.5b.json").read_text())
@@ -30,7 +31,7 @@ def test_qwen2_step_matches_hand_arithmetic():
         + 3 * 1536 * 8960 + 2 * 1536) + 1536
     assert params == work.param_count(QWEN2) == 1_543_714_304
     kv_tok = 28 * 2 * 2 * 128 * 2
-    assert kv_tok == work.kv_bytes_per_token(QWEN2) == 28_672
+    assert kv_tok == dense_gqa.kv_bytes_per_token(QWEN2) == 28_672
     assert nbytes == 2 * params + 4000 * kv_tok
 
 
@@ -54,7 +55,7 @@ def test_count_reads_only_shapes_and_context_lengths():
     cfg = Watched(QWEN2)
     base = work.decode_step_work(cfg, [10, 20, 30])
     assert cfg.read <= {
-        "hidden_size", "num_attention_heads", "num_key_value_heads",
+        "arch", "hidden_size", "num_attention_heads", "num_key_value_heads",
         "head_dim", "intermediate_size", "num_hidden_layers", "vocab_size",
         "attention_bias", "qk_norm", "tie_word_embeddings"}
     padded = dict(QWEN2, max_len=10240, max_batch=40, num_pages=1)
